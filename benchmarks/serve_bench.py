@@ -100,6 +100,7 @@ import jax
 from repro.configs import get_config
 from repro.core.config import PSQ_TERNARY
 from repro.kernels import registry
+from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.serve import (
     EngineConfig, PackedModelCache, ServeEngine, pack_tree_psq,
@@ -579,7 +580,7 @@ def bench_moe(args) -> Dict:
     while e * 2 <= len(jax.devices()) and cfg.n_experts % (e * 2) == 0:
         e *= 2
     if e > 1:
-        mesh = jax.make_mesh((1, 1, e), ("data", "model", "expert"))
+        mesh = make_mesh((1, 1, e), ("data", "model", "expert"))
         out["expert_parallel"] = dict(
             mesh=f"data=1,model=1,expert={e}",
             **bench_mode("continuous", params, cfg, trace, slots, max_len,
@@ -885,7 +886,7 @@ def run_sharded_sweep(args) -> List[Dict]:
         m *= 2
     entries: List[Dict] = []
     for m in sizes:
-        mesh = jax.make_mesh((1, m), ("data", "model"))
+        mesh = make_mesh((1, m), ("data", "model"))
         packed = pack_tree_psq(params, qcfg, cache, mesh=mesh)
         r = bench_mode("continuous", packed, cfg, trace, slots, max_len,
                        mesh=mesh)
@@ -947,6 +948,9 @@ def main() -> None:
         from repro.launch.mesh import force_host_device_count
 
         force_host_device_count(args.devices)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     result = run(args)
     result["kernel_backends"] = registry.describe()
     with open(args.out, "w") as f:

@@ -40,8 +40,9 @@ class QuantConfig:
     collect_stats: bool = False             # export ternary sparsity etc.
     use_kernel: bool = False                # kernel path vs jnp QAT reference
     # named implementation from repro.kernels.registry; None -> process
-    # default ("pallas-interpret" unless overridden). Setting a backend
-    # implies the kernel path (see ``kernel_path``).
+    # default (compiled "pallas" on a TPU, "pallas-interpret" elsewhere,
+    # unless overridden). Setting a backend implies the kernel path (see
+    # ``kernel_path``).
     kernel_backend: Optional[str] = None
     fuse_planes: bool = False               # single-MXU-pass bit-plane fusion
     # skip all-zero ternary column blocks using pack-time occupancy

@@ -23,7 +23,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.core import psq
@@ -152,8 +151,8 @@ def serve_linear_tp(
         )
         return jax.lax.psum(full, axis)
 
-    fn = shard_map(local_fn, mesh=mesh, in_specs=(specs, xspec),
-                   out_specs=xspec, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=(specs, xspec),
+                       out_specs=xspec, check_vma=False)
     return fn(layer, x), {}
 
 

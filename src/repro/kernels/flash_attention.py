@@ -44,14 +44,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_len: int, kv_block: int,
 
     def body(i, carry):
         m, l, acc = carry
-        # size-1 slice, not a bare int 0: this JAX's interpret-mode
-        # discharge rule requires Slice-or-array indices in pl.load
-        k = pl.load(
-            k_ref, (pl.ds(0, 1), pl.ds(i * kv_block, kv_block), slice(None))
-        )[0]
-        v = pl.load(
-            v_ref, (pl.ds(0, 1), pl.ds(i * kv_block, kv_block), slice(None))
-        )[0]
+        k = k_ref[0, pl.ds(i * kv_block, kv_block), :]
+        v = v_ref[0, pl.ds(i * kv_block, kv_block), :]
         logits = jax.lax.dot_general(
             q, k.astype(jnp.float32),
             (((1,), (1,)), ((), ())),

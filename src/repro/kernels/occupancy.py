@@ -117,39 +117,30 @@ def column_occupancy(
     >>> occ.mean_zero_fraction, occ.skippable_block_fraction
     (1.0, 1.0)
     """
-    w = np.asarray(w_int, dtype=np.float64)
+    w = np.asarray(w_int)
     if w.ndim != 2:
         raise ValueError(f"column_occupancy needs a 2-D (K, O) weight, "
                          f"got shape {w.shape}")
     k, o = w.shape
     t = math.ceil(k / xbar_rows)
     kp = t * xbar_rows
-    w = np.pad(w, ((0, kp - k), (0, 0))).reshape(t, xbar_rows, o)
-
-    zero_cols = np.all(w == 0.0, axis=1)                       # (T, O)
-    u = np.mod(w, float(2 ** n_w))
-    plane_zero = np.stack(
-        [np.all(np.mod(np.floor(u / 2.0 ** j), 2.0) == 0.0, axis=1)
-         for j in range(n_w)], axis=1,
-    )                                                          # (T, n_w, O)
+    w = np.pad(w.astype(np.int32), ((0, kp - k), (0, 0)))
+    # OR over each tile's rows: zero iff every code is zero; bit j of the
+    # two's-complement codes' OR is zero iff plane j is zero in that tile
+    bits = np.bitwise_or.reduce(w.reshape(t, xbar_rows, o), axis=1)  # (T, O)
+    zero_cols = bits == 0                                      # (T, O)
+    plane_zero = np.stack([((bits >> j) & 1) == 0 for j in range(n_w)],
+                          axis=1)                              # (T, n_w, O)
 
     nb = math.ceil(o / block)
-    zb, zf, pf = [], [], []
-    for ti in range(t):
-        zb_row, zf_row, pf_row = [], [], []
-        for bi in range(nb):
-            sl = slice(bi * block, min((bi + 1) * block, o))
-            zb_row.append(bool(np.all(zero_cols[ti, sl])))
-            zf_row.append(float(np.mean(zero_cols[ti, sl])))
-            pf_row.append(tuple(
-                float(np.mean(plane_zero[ti, j, sl])) for j in range(n_w)
-            ))
-        zb.append(tuple(zb_row))
-        zf.append(tuple(zf_row))
-        # store as (n_w, NB) per tile
-        pf.append(tuple(
-            tuple(pf_row[bi][j] for bi in range(nb)) for j in range(n_w)
-        ))
+    starts = np.arange(nb) * block
+    cols = np.minimum(starts + block, o) - starts              # (NB,)
+    zero_n = np.add.reduceat(zero_cols.astype(np.int32), starts, axis=-1)
+    plane_n = np.add.reduceat(plane_zero.astype(np.int32), starts, axis=-1)
+    zb = tuple(tuple(row) for row in (zero_n == cols).tolist())
+    zf = tuple(tuple(row) for row in (zero_n / cols).tolist())
+    pf = tuple(tuple(tuple(r) for r in tile)
+               for tile in (plane_n / cols).tolist())
     return ColumnOccupancy(
         n_cols=o, n_tiles=t, n_w=n_w, block=block,
         zero_blocks=tuple(zb), zero_col_frac=tuple(zf),

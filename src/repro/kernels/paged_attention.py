@@ -106,12 +106,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref,
         m, l, acc = carry
         blk = bt_row[i]
         # one page of this program's kv head, streamed through VMEM
-        k = pl.load(
-            k_ref, (pl.ds(blk, 1), slice(None), pl.ds(0, 1), slice(None))
-        )[0, :, 0]                                       # (bs, D)
-        v = pl.load(
-            v_ref, (pl.ds(blk, 1), slice(None), pl.ds(0, 1), slice(None))
-        )[0, :, 0]
+        k = k_ref[blk, :, 0, :]                          # (bs, D)
+        v = v_ref[blk, :, 0, :]
         logits = jax.lax.dot_general(
             q, k.astype(jnp.float32), (((1,), (1,)), ((), ()))
         )                                                # (G, bs)

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.occupancy import (
     ColumnOccupancy,
@@ -53,6 +54,15 @@ def _py_bit_weights(n: int):
 
 def _extract_bit(u: jax.Array, k: int) -> jax.Array:
     return jnp.mod(jnp.floor(u / float(2 ** k)), 2.0)
+
+
+def _bit_dot(a, b):
+    # {0,1} bf16 operands sum exactly in one bf16 MXU pass. The precision
+    # is pinned so that a caller's default_matmul_precision("highest")
+    # cannot ask Mosaic for an fp32 contraction of bf16 operands, which it
+    # refuses.
+    return jax.lax.dot(a, b, precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=jnp.float32)
 
 
 def _comparator(a, alpha, levels: str):
@@ -79,7 +89,7 @@ def _psq_kernel(
 ):
     t = pl.program_id(2)
     x = x_ref[...].astype(jnp.float32)       # (BB, R) integer-valued
-    alpha = alpha_ref[0, 0]
+    alpha = alpha_ref[0, 0]                  # SMEM scalar
     sigma = _py_bit_weights(n_a)             # python floats: static constants
     kappa = _py_bit_weights(n_w)
     c_w = sum(kappa)
@@ -99,7 +109,7 @@ def _psq_kernel(
                 xb = _extract_bit(u_x, j).astype(jnp.bfloat16)
                 for k in range(n_w):
                     wb = _extract_bit(u_w, k).astype(jnp.bfloat16)
-                    ps = jax.lax.dot(xb, wb, preferred_element_type=jnp.float32)
+                    ps = _bit_dot(xb, wb)
                     code = jnp.clip(
                         jnp.sign(ps) * jnp.floor(jnp.abs(ps) / step + 0.5),
                         0.0, qmax,
@@ -114,8 +124,7 @@ def _psq_kernel(
             wb_all = jnp.concatenate(
                 [_extract_bit(u_w, k) for k in range(n_w)], axis=1
             ).astype(jnp.bfloat16)                           # (R, n_w*BO)
-            ps_all = jax.lax.dot(xb_all, wb_all,
-                                 preferred_element_type=jnp.float32)
+            ps_all = _bit_dot(xb_all, wb_all)
             rows_all = jnp.sum(xb_all.astype(jnp.float32), axis=1,
                                keepdims=True)
             acc = jnp.zeros((bb, bo), jnp.float32)
@@ -136,7 +145,7 @@ def _psq_kernel(
             xb16 = xb.astype(jnp.bfloat16)
             for k in range(n_w):
                 wb = _extract_bit(u_w, k).astype(jnp.bfloat16)
-                ps = jax.lax.dot(xb16, wb, preferred_element_type=jnp.float32)
+                ps = _bit_dot(xb16, wb)
                 a = 2.0 * ps - rowsum
                 p = _comparator(a, alpha, levels)
                 sf = sf_ref[0, j, k, :].astype(jnp.float32)
@@ -172,11 +181,11 @@ def _psq_kernel(
     elif levels == "adc":
         # a zero block contributes an exact 0 under ADC quantization:
         # skipping is simply not accumulating
-        @pl.when(z_ref[0, 0] == 0)
+        @pl.when(z_ref[t, pl.program_id(1)] == 0)
         def _adc_dense():
             o_ref[...] += _dense_acc()
     else:
-        flag = z_ref[0, 0]
+        flag = z_ref[t, pl.program_id(1)]    # this (tile, column block)
 
         @pl.when(flag == 0)
         def _dense():
@@ -263,8 +272,10 @@ def psq_matmul_kernel(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda bi, oi, ti: (0, 0)),
-            pl.BlockSpec((1, 1), lambda bi, oi, ti: (ti, oi)),
+            # scalars and the (T, O_pad/BO) skip flags live whole in SMEM:
+            # Mosaic refuses VMEM blocks below the (8, 128) tile
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bb, r), lambda bi, oi, ti: (bi, ti)),
             pl.BlockSpec((r, bo), lambda bi, oi, ti: (ti, oi)),
             pl.BlockSpec((1, n_a, n_w, bo), lambda bi, oi, ti: (ti, 0, 0, oi)),

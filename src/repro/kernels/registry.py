@@ -15,8 +15,13 @@ Built-in backends:
   pallas-interpret Pallas kernels in interpret mode — runs anywhere
                    (CPU containers included), exercises the real kernel
                    code path minus Mosaic lowering.
-  pallas           compiled Pallas kernels — TPU/GPU only; the serving
+  pallas           compiled Pallas kernels — TPU only; the serving
                    fast path.
+
+With nothing pinned, the default follows the platform: compiled
+``pallas`` on a TPU, ``pallas-interpret`` elsewhere (the only Pallas a
+CPU has). A TPU run reaches the interpreter or the ``reference`` oracle
+only when a caller names them.
 
 Backends expose three entry points with fixed signatures:
 
@@ -109,7 +114,8 @@ class KernelBackend:
 
 
 _REGISTRY: Dict[str, KernelBackend] = {}
-_DEFAULT_NAME = "pallas-interpret"
+# None: follow the platform (see default_backend)
+_DEFAULT_NAME: Optional[str] = None
 
 
 def register_backend(backend: KernelBackend) -> KernelBackend:
@@ -198,22 +204,23 @@ def get_backend(name: Optional[str] = None) -> KernelBackend:
     return backend.require_available()
 
 
-def set_default_backend(name: str) -> None:
+def set_default_backend(name: Optional[str]) -> None:
     """Process-wide default used when a config does not pin a backend.
 
-    (``REPRO_KERNEL_BACKEND`` in the environment still beats this — the
-    example sets it aside to show the in-process value, then restores.)
+    ``None`` restores the platform default. (``REPRO_KERNEL_BACKEND`` in
+    the environment still beats this — the example sets it aside to
+    show the in-process value, then restores.)
 
     >>> import os
     >>> saved = os.environ.pop("REPRO_KERNEL_BACKEND", None)
     >>> set_default_backend("reference")
     >>> default_backend()
     'reference'
-    >>> set_default_backend("pallas-interpret")   # restore the built-in
+    >>> set_default_backend(None)   # restore the platform default
     >>> if saved is not None: os.environ["REPRO_KERNEL_BACKEND"] = saved
     """
     global _DEFAULT_NAME
-    if name not in _REGISTRY:
+    if name is not None and name not in _REGISTRY:
         raise KeyError(
             f"unknown kernel backend {name!r}; "
             f"registered: {registered_backends()}"
@@ -222,7 +229,9 @@ def set_default_backend(name: str) -> None:
 
 
 def default_backend() -> str:
-    """Env override (``REPRO_KERNEL_BACKEND``) beats the in-process default.
+    """Env override (``REPRO_KERNEL_BACKEND``) beats the in-process
+    default, which beats the platform's: compiled ``pallas`` on a TPU,
+    ``pallas-interpret`` anywhere else.
 
     >>> import os
     >>> saved = os.environ.pop("REPRO_KERNEL_BACKEND", None)
@@ -230,7 +239,9 @@ def default_backend() -> str:
     True
     >>> if saved is not None: os.environ["REPRO_KERNEL_BACKEND"] = saved
     """
-    return os.environ.get(_ENV_VAR) or _DEFAULT_NAME
+    return (os.environ.get(_ENV_VAR) or _DEFAULT_NAME
+            or ("pallas" if _compiled_pallas_available()
+                else "pallas-interpret"))
 
 
 def resolve_backend(cfg) -> KernelBackend:
@@ -325,9 +336,9 @@ def _pallas_paged(interpret: bool):
 
 
 def _compiled_pallas_available() -> bool:
-    # pallas_call only lowers through Mosaic/Triton on accelerators;
-    # CPU supports interpret mode exclusively.
-    return jax.default_backend() in ("tpu", "gpu", "cuda", "rocm")
+    # the kernels lower through Mosaic (SMEM/VMEM block specs): TPU only;
+    # every other platform runs them in interpret mode exclusively.
+    return jax.default_backend() == "tpu"
 
 
 register_backend(KernelBackend(
@@ -348,7 +359,7 @@ register_backend(KernelBackend(
 
 register_backend(KernelBackend(
     name="pallas",
-    description="compiled Pallas kernels (TPU/GPU serving fast path)",
+    description="compiled Pallas kernels (TPU serving fast path)",
     psq_matmul=_pallas_psq(interpret=False),
     int4_matmul=_pallas_int4(interpret=False),
     is_available=_compiled_pallas_available,

@@ -2,8 +2,12 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --requests 8 [--int4 | --psq-packed] [--backend reference] \
-        [--slots 4] [--mode auto|continuous|static] \
+        [--slots 4] [--mode auto|continuous|static] [--reduced] \
         [--decode-horizon H] [--mesh DATA,MODEL] [--devices N]
+
+The published configuration is served by default; ``--reduced`` swaps
+in the small same-family config (``ArchConfig.reduced``) that a CPU
+can initialise and serve in seconds — use it for CPU demos.
 
 Every family — KV-cache, recurrent-state (SSM/xLSTM/hybrid) AND the
 side-input families (encdec cross-KV, VLM patch embeds) — serves
@@ -98,13 +102,17 @@ class StreamingFrontend:
         return self.engine.drained
 
 
-def _parse_args():
+def _parse_args(argv=None):
     # configs/argparse only — jax is imported after --devices is applied
     from repro.configs import list_archs
     from repro.kernels import registry
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b", choices=list_archs())
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the small same-family config (d_model "
+                         "128, <=3 layers, vocab 256) instead of the "
+                         "published widths; for CPU demos")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=128)
@@ -116,7 +124,8 @@ def _parse_args():
     ap.add_argument("--backend", default=None,
                     choices=registry.registered_backends(),
                     help="kernel backend for --psq-packed "
-                         "(default: 'reference' on CPU)")
+                         "(default: 'reference' on CPU, else the "
+                         "platform's default, compiled 'pallas' on TPU)")
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--slots", type=int, default=4,
                     help="decode slot-pool size (static: batch size)")
@@ -171,11 +180,12 @@ def _parse_args():
     ap.add_argument("--devices", type=int, default=0,
                     help="CPU only: forge N virtual devices via XLA_FLAGS "
                          "(must be the first JAX use in the process)")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-def main():
-    args = _parse_args()
+def main(argv=None):
+    """Serve one seeded workload; returns the finished requests."""
+    args = _parse_args(argv)
     if args.devices:
         from repro.launch.mesh import force_host_device_count
 
@@ -188,14 +198,16 @@ def main():
     from repro.core.config import PSQ_TERNARY
     from repro.core.psq_linear import pack_tree_for_serving
     from repro.kernels import registry
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_host_mesh, make_mesh
     from repro.models import init_model
     from repro.serve import (
         EngineConfig, PackedModelCache, ServeEngine, pack_tree_psq,
         throughput_stats,
     )
 
-    cfg = get_config(args.arch).reduced()
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     if args.mesh:
         sizes = tuple(int(v) for v in args.mesh.split(","))
         if len(sizes) not in (2, 3):
@@ -215,7 +227,7 @@ def main():
                 f"--mesh {args.mesh}: an expert axis > 1 only applies to "
                 f"MoE archs; {args.arch} has no experts"
             )
-        mesh = jax.make_mesh(sizes, axes)
+        mesh = make_mesh(sizes, axes)
     else:
         mesh = make_host_mesh()
     print(f"[serve] mesh: "
@@ -224,7 +236,8 @@ def main():
 
     if args.psq_packed:
         backend = args.backend or (
-            "reference" if jax.default_backend() == "cpu" else "pallas"
+            "reference" if jax.default_backend() == "cpu"
+            else registry.default_backend()
         )
         qcfg = dataclasses.replace(PSQ_TERNARY, kernel_backend=backend)
         cfg = cfg.with_quant(qcfg)
@@ -240,8 +253,10 @@ def main():
 
     draft_cfg, draft_params = None, None
     if args.spec_k:
-        draft_cfg = (get_config(args.draft).reduced() if args.draft
+        draft_cfg = (get_config(args.draft) if args.draft
                      else dataclasses.replace(cfg, n_layers=1))
+        if args.draft and args.reduced:
+            draft_cfg = draft_cfg.reduced()
         draft_params = init_model(jax.random.PRNGKey(1), draft_cfg)
         print(f"[serve] spec decode: k={args.spec_k}, draft "
               f"{args.draft or '1-layer copy'} "
@@ -312,7 +327,11 @@ def main():
           f"{sched['energy_pj_per_request']:.1f} pJ/request, "
           f"edap {sched['edap_total']:.3g}, "
           f"mean occupancy {sched['mean_occupancy']:.3f}")
+    return done
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

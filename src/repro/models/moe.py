@@ -21,7 +21,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.config import QuantConfig
@@ -203,9 +202,9 @@ def _apply_moe_ep(
         y = jax.vmap(_combine_chunk)(gl, ys, sel_idx)
         return y, aux, me
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh, in_specs=(pspecs, gspec),
-        out_specs=(gspec, aux_spec, me_spec), check_rep=False,
+        out_specs=(gspec, aux_spec, me_spec), check_vma=False,
     )
     return fn(p, groups)
 

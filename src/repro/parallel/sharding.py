@@ -9,7 +9,7 @@ code runs everywhere:
     >>> import jax, jax.numpy as jnp
     >>> constrain(jnp.ones((4, 8)), "batch", "ffn").shape  # no rules: no-op
     (4, 8)
-    >>> mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+    >>> mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
     >>> with axis_rules(RULES_2D, mesh):
     ...     logical_to_pspec(["batch", "seq", "ffn"], shape=(4, 8, 16))
     PartitionSpec('data', None, 'model')
@@ -148,8 +148,13 @@ def logical_to_pspec(
 
 
 def constrain(x: jax.Array, *logical: Optional[str]) -> jax.Array:
-    """Apply a logical sharding constraint (no-op without active rules)."""
-    if _STATE.rules is None:
+    """Apply a logical sharding constraint (no-op without active rules).
+
+    Empty arrays (e.g. the shared-attention KV of a hybrid with no
+    attention groups) hold nothing to place and pass through: an eager
+    constraint on one comes back replicated, which JAX rejects.
+    """
+    if _STATE.rules is None or x.size == 0:
         return x
     spec = logical_to_pspec(logical, shape=x.shape)
     if _STATE.mesh is not None:

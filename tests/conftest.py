@@ -26,8 +26,6 @@ def compile_counts():
 
     Returns ``counts(*fns) -> List[int]``: the per-function jit cache
     sizes, read through the private ``_cache_size`` introspection hook.
-    On a jax build without the hook the calling test skips (one message,
-    one place) instead of every suite carrying its own hasattr guard.
 
     The canonical pins (see docs/testing.md):
 
@@ -39,7 +37,5 @@ def compile_counts():
       compilations.
     """
     def counts(*fns):
-        if not all(hasattr(f, "_cache_size") for f in fns):
-            pytest.skip("jax version without jit _cache_size introspection")
         return [f._cache_size() for f in fns]
     return counts

@@ -89,8 +89,7 @@ def test_stats_keys_and_pinned_closures(tiny):
     for name in PINNED_CLOSURES:
         fn = getattr(eng, name)
         assert callable(fn), name
-        if hasattr(fn, "_cache_size"):
-            assert fn._cache_size() >= 0
+        assert fn._cache_size() >= 0
 
 
 def test_paged_engine_pinned_closures(tiny):

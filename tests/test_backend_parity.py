@@ -169,15 +169,33 @@ class TestRegistry:
         assert registry.default_backend() == "reference"
         assert registry.get_backend(None).name == "reference"
 
-    def test_set_default_backend(self):
-        old = registry.default_backend()
+    def test_set_default_backend(self, monkeypatch):
+        monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+        platform_default = registry.default_backend()
         try:
             registry.set_default_backend("reference")
             assert registry.default_backend() == "reference"
         finally:
-            registry.set_default_backend(old)
+            registry.set_default_backend(None)
+        assert registry.default_backend() == platform_default
         with pytest.raises(KeyError):
             registry.set_default_backend("no-such-backend")
+
+    @pytest.mark.parametrize("platform,expected", [
+        ("tpu", "pallas"),
+        ("cpu", "pallas-interpret"),
+    ])
+    def test_platform_default(self, monkeypatch, platform, expected):
+        """Unpinned kernel configs run compiled Pallas on a TPU — never
+        the interpreter or the oracle — and interpret mode elsewhere."""
+        monkeypatch.delenv("REPRO_KERNEL_BACKEND", raising=False)
+        monkeypatch.setattr(registry, "_DEFAULT_NAME", None)
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert registry.default_backend() == expected
+        unpinned = QuantConfig(mode="psq", use_kernel=True)
+        assert registry.resolve_backend(unpinned).name == expected
+        assert ("pallas" in registry.available_backends()) == (
+            platform == "tpu")
 
     def test_config_kernel_path_property(self):
         assert not QuantConfig(mode="psq").kernel_path

@@ -18,8 +18,8 @@ Two extensions of the same contract:
   (the draft's scanned propose step and the masked verify forward),
   and a second admission wave replays both from cache.
 
-The shared ``compile_counts`` fixture (tests/conftest.py) owns the
-``_cache_size`` introspection guard; see docs/testing.md for the test
+The shared ``compile_counts`` fixture (tests/conftest.py) reads the
+``_cache_size`` introspection hook; see docs/testing.md for the test
 taxonomy this belongs to.
 """
 import dataclasses

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.serve import EngineConfig, ServeEngine, throughput_stats
 
@@ -79,7 +80,7 @@ class TestHorizonParity:
         """The data-sharded slot pool (batch/recurrent_state -> data)
         decodes identically under the device loop on a 2-way mesh."""
         cfg, params = models[arch]
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         out, _ = _run(cfg, params, _trace(cfg), decode_horizon=4, mesh=mesh)
         assert out == host_refs[arch]
 

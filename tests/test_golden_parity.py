@@ -37,6 +37,7 @@ import jax
 
 from repro.configs import get_config
 from repro.core.config import PSQ_TERNARY
+from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.serve import (
     EngineConfig, PackedModelCache, ServeEngine, pack_tree_psq,
@@ -150,7 +151,7 @@ class TestGoldenParity:
     def test_data_sharded_matches_golden(self, arch):
         case = _load(arch)
         cfg, params = _fp_model(arch)
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         assert _serve(cfg, params, case, mesh=mesh) == case["outputs"], \
             f"{arch}: data-sharded engine diverged from the golden"
 
@@ -159,7 +160,7 @@ class TestGoldenParity:
         arch = "granite-moe-3b-a800m"
         case = _load(arch)
         cfg, params = _fp_model(arch)
-        mesh = jax.make_mesh((1, 1, 4), ("data", "model", "expert"))
+        mesh = make_mesh((1, 1, 4), ("data", "model", "expert"))
         assert _serve(cfg, params, case, mesh=mesh) == case["outputs"], \
             "expert-parallel MoE serving diverged from the golden"
 
@@ -186,7 +187,7 @@ class TestGoldenParity:
         on the golden."""
         case = _load(arch)
         cfg, params = _fp_model(arch)
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         assert _serve(cfg, params, case, mesh=mesh,
                       mode="continuous") == case["outputs"], \
             f"{arch}: 2-way data-sharded continuous diverged"

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_analysis import analyze
+from repro.launch.mesh import make_mesh
 
 jax.config.update("jax_platform_name", "cpu")
 W = jax.ShapeDtypeStruct((512, 512), jnp.float32)
@@ -80,7 +81,7 @@ class TestBytes:
 
 class TestCollectives:
     def test_psum_counted(self):
-        mesh = jax.make_mesh((1,), ("x",))
+        mesh = make_mesh((1,), ("x",))
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         def f(a):

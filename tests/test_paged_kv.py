@@ -12,6 +12,7 @@ from repro.kernels.paged_attention import (
     paged_attention_kernel,
     paged_attention_ref,
 )
+from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.serve import EngineConfig, ServeEngine
 from repro.serve.paged_kv import (
@@ -368,7 +369,7 @@ class TestPagedEngineParity:
         engine == single-device engine, token for token."""
         cfg, params = tiny
         base, _ = _run_engine(params, cfg, shared_prompts, max_new=4)
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         out, eng = _run_engine(params, cfg, shared_prompts, mesh=mesh,
                                max_new=4, paged=True, prefix_reuse=True)
         assert out == base

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.serve import EngineConfig, ServeEngine
 
@@ -106,7 +107,7 @@ class TestContinuousParity:
         cfg, params = models[arch]
         prompts = _prompts(cfg)
         base, _ = _run(params, cfg, prompts, "continuous")
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         out, eng = _run(params, cfg, prompts, "continuous", mesh=mesh)
         assert out == base, f"{arch}: 2-way data mesh diverged"
         assert eng.stats()["mesh"] == "data=2xmodel=1"

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.serve import EngineConfig, Request, ServeEngine, throughput_stats
 
@@ -248,7 +249,7 @@ class TestShardedServing:
     def test_fp_decode_parity_2way(self, tiny, prompts, shape):
         cfg, params = tiny
         base, _ = self._run(params, cfg, prompts)
-        mesh = jax.make_mesh(shape, ("data", "model"))
+        mesh = make_mesh(shape, ("data", "model"))
         out, eng = self._run(params, cfg, prompts, mesh=mesh)
         assert out == base, f"mesh {shape} diverged from single-device"
         assert eng.stats()["mesh"] == f"data={shape[0]}xmodel={shape[1]}"
@@ -272,7 +273,7 @@ class TestShardedServing:
         packed = pack_tree_psq(params, qcfg, cache)
         base, _ = self._run(packed, qc, prompts, max_new=4)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         packed_sh = pack_tree_psq(params, qcfg, cache, mesh=mesh)
         # sharded packing of identical weights is a pure cache hit
         assert cache.stats()["packs"] == cache.stats()["layers"]
@@ -286,7 +287,7 @@ class TestShardedServing:
         """The no-recompile contract survives sharding: decode compiles
         once, a repeated workload adds zero compilations."""
         cfg, params = tiny
-        mesh = jax.make_mesh((2, 1), ("data", "model"))
+        mesh = make_mesh((2, 1), ("data", "model"))
         eng = ServeEngine(params, cfg,
                           EngineConfig(max_batch=4, max_len=64), mesh=mesh)
         fns = [eng._decode_multi, eng._prefill_bucket, eng._insert]
@@ -337,7 +338,7 @@ class TestModeResolution:
         if with_mesh:
             if len(jax.devices()) < 2:
                 pytest.skip("needs >= 2 devices")
-            mesh = jax.make_mesh((2, 1), ("data", "model"))
+            mesh = make_mesh((2, 1), ("data", "model"))
         cfg = get_config(arch).reduced()
         eng = ServeEngine(None, cfg,
                           EngineConfig(max_batch=2, max_len=32,

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel.sharding import (
     RULES_2D, axis_rules, constrain, logical_to_pspec,
     packed_layer_pspecs, shard_packed_layer, shard_packed_tree, tp_axes,
@@ -25,13 +26,13 @@ class TestLogicalRules:
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
     def test_pspec_mapping(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         with axis_rules(RULES_2D, mesh):
             spec = logical_to_pspec(["batch", "seq", "ffn"], shape=(4, 8, 16))
         assert spec == P("data", None, "model")
 
     def test_divisibility_guard(self):
-        mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+        mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
         with axis_rules(RULES_2D, mesh):
             # 7 not divisible by model=2 -> unsharded
             spec = logical_to_pspec(["batch", "ffn"], shape=(4, 7))
@@ -39,7 +40,7 @@ class TestLogicalRules:
 
     def test_duplicate_axis_dedup(self):
         """Two logical dims mapping to the same mesh axis: first wins."""
-        mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+        mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
         with axis_rules(RULES_2D, mesh):
             spec = logical_to_pspec(
                 ["experts", None, "expert_ffn"], shape=(4, 2, 8)
@@ -51,7 +52,7 @@ class TestParamSpecs:
     def test_qkv_and_down_proj_rules(self):
         from repro.launch.specs import param_pspec
 
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
 
         class Leaf:
             def __init__(self, shape):
@@ -84,7 +85,7 @@ class TestParamSpecs:
             def __init__(self, key):
                 self.key = key
 
-        mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+        mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
         # 128 experts divisible by 2 -> EP
         spec = param_pspec([K("moe"), K("w_gate")], Leaf((35, 128, 64, 32)),
                            mesh)
@@ -110,7 +111,7 @@ def _packed_layer(k_in=64, n_out=8, use_bias=True, seed=0, **qkw):
 class TestPackedLayerSpecs:
     def test_column_dims_follow_sf_out_rule(self):
         layer, _ = _packed_layer()
-        mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+        mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
         specs = packed_layer_pspecs(layer, rules=RULES_2D, mesh=mesh)
         assert specs.w_codes == P(None, "model")
         assert specs.w_packed == P(None, "model")
@@ -124,14 +125,14 @@ class TestPackedLayerSpecs:
 
     def test_reduced_granularity_sf_stays_replicated(self):
         layer, _ = _packed_layer(sf_granularity="per_tile")
-        mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+        mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
         specs = packed_layer_pspecs(layer, rules=RULES_2D, mesh=mesh)
         assert layer.sf_q.shape[-1] == 1
         assert specs.sf_q == P()         # size-1 dim: divisibility guard
 
     def test_non_divisible_columns_fall_back_unsharded(self):
         layer, _ = _packed_layer(n_out=6)
-        mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 4)))
+        mesh = jax.sharding.AbstractMesh((1, 4), ("data", "model"))
         specs = packed_layer_pspecs(layer, rules=RULES_2D, mesh=mesh)
         assert specs.w_codes == P()
         assert specs.bias == P()
@@ -146,7 +147,7 @@ class TestPackedLayerSpecs:
         stacked = jax.vmap(
             lambda k: PackedLayer.pack(init_linear(k, 64, 8, cfg), cfg)
         )(jax.random.split(jax.random.PRNGKey(0), 3))
-        mesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+        mesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
         specs = packed_layer_pspecs(stacked, rules=RULES_2D, mesh=mesh)
         assert stacked.w_codes.ndim == 3
         assert specs.w_codes == P(None, None, "model")
@@ -155,18 +156,18 @@ class TestPackedLayerSpecs:
 
     def test_tp_axes_activation(self):
         assert tp_axes() is None                       # no rules active
-        mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+        mesh1 = make_mesh((1, 1), ("data", "model"))
         with axis_rules(RULES_2D, mesh1):
             assert tp_axes() is None                   # model axis size 1
         with axis_rules(RULES_2D, None):
             assert tp_axes() is None                   # rules without mesh
-        amesh = jax.sharding.AbstractMesh((("data", 1), ("model", 2)))
+        amesh = jax.sharding.AbstractMesh((1, 2), ("data", "model"))
         with axis_rules(RULES_2D, amesh):
             assert tp_axes() is None                   # abstract: no shard_map
 
     @needs_devices(2)
     def test_tp_axes_on_real_mesh(self):
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         with axis_rules(RULES_2D, mesh):
             assert tp_axes() == (mesh, "model")
 
@@ -185,7 +186,7 @@ class TestTensorParallelPSQ:
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 3, 64))
         y_ref, _ = layer.apply_serving(x)
 
-        mesh = jax.make_mesh((1, model_parallel), ("data", "model"))
+        mesh = make_mesh((1, model_parallel), ("data", "model"))
         with axis_rules(RULES_2D, mesh):
             y_tp, _ = apply_linear(layer, x, qcfg)
         np.testing.assert_array_equal(np.asarray(y_ref), np.asarray(y_tp))
@@ -197,7 +198,7 @@ class TestTensorParallelPSQ:
         layer, qcfg = _packed_layer(n_out=6)     # 6 % 4 != 0
         x = jax.random.normal(jax.random.PRNGKey(2), (2, 64))
         y_ref, _ = layer.apply_serving(x)
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        mesh = make_mesh((1, 4), ("data", "model"))
         with axis_rules(RULES_2D, mesh):
             y, _ = apply_linear(layer, x, qcfg)
         np.testing.assert_array_equal(np.asarray(y_ref), np.asarray(y))
@@ -209,7 +210,7 @@ class TestTensorParallelPSQ:
         layer, qcfg = _packed_layer(k_in=64, n_out=16)
         x = jax.random.normal(jax.random.PRNGKey(3), (4, 64))
         y_ref, _ = layer.apply_serving(x)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
 
         def fwd(lyr, xx):
             with axis_rules(RULES_2D, mesh):
@@ -223,7 +224,7 @@ class TestTensorParallelPSQ:
         from jax.sharding import NamedSharding
 
         layer, _ = _packed_layer(n_out=8)
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         placed = shard_packed_layer(layer, mesh)
         assert placed.w_codes.sharding == NamedSharding(
             mesh, P(None, "model"))
@@ -244,7 +245,7 @@ class TestTensorParallelPSQ:
                            kernel_backend="reference")
         tree = {"mlp": init_linear(jax.random.PRNGKey(0), 64, 8, qcfg)}
         cache = PackedModelCache()
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
 
         sharded = pack_tree_psq(tree, qcfg, cache, mesh=mesh)
         assert sharded["mlp"].w_codes.sharding.spec == P(None, "model")
@@ -259,7 +260,7 @@ class TestTensorParallelPSQ:
         layer, _ = _packed_layer(n_out=8)
         norm = {"scale": jnp.ones((64,))}
         tree = {"mlp": layer, "norm": norm, "depth": [layer]}
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         out = shard_packed_tree(tree, mesh)
         assert out["norm"]["scale"] is norm["scale"]   # leaf passes through
         assert out["mlp"].w_codes.sharding.spec == P(None, "model")

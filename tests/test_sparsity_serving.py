@@ -30,6 +30,7 @@ from repro.kernels.occupancy import (
     occupancy_for_kernel, shard_occupancy,
 )
 from repro.kernels.ref import psq_matmul_ref
+from repro.launch.mesh import make_mesh
 from repro.serve.cache import PackedLayer, PackedModelCache, pack_tree_psq
 
 from tests._hypothesis_compat import given, settings, st
@@ -283,7 +284,7 @@ class TestPackedOccupancy:
         w[:, :META_BLOCK] = 0.0
         params["w"] = jnp.asarray(w)
         cache = PackedModelCache()
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         placed = pack_tree_psq({"mlp": params}, cfg, cache, mesh=mesh)
         assert placed["mlp"].occupancy is not None
         assert placed["mlp"].occupancy.skippable_block_fraction == 0.5
@@ -425,7 +426,7 @@ class TestShardOccupancy:
             layer, cfg=dataclasses.replace(qcfg, sparsity_skip=False))
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 96))
         y_ref, _ = dense_layer.apply_serving(x)     # single-device dense
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         with axis_rules(RULES_2D, mesh):
             y_skip, _ = apply_linear(layer, x, qcfg)
             y_dense, _ = apply_linear(dense_layer, x, dense_layer.cfg)
@@ -465,7 +466,7 @@ class TestShardOccupancy:
             return node
 
         base = get_config("tinyllama-1.1b").reduced()
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         outs = {}
         for skip in (True, False):
             qcfg = dataclasses.replace(PSQ_TERNARY,
