@@ -1,0 +1,15 @@
+"""Prefill's share of the chip's bf16 peak: dense-equivalent model FLOPs
+of the prompt tokens prefilled in the traced window (2 x parameters x
+tokens, causal attention added) over the prefill programs' device
+time."""
+from bench import work
+
+
+def read(ctx):
+    tokens = ctx.counts["prefill_tokens"]
+    dev_s = ctx.trace["phase_s"]["prefill"]
+    if not tokens or dev_s <= 0:
+        return None
+    flops = work.model_flops(ctx.cfg, ctx.layers, tokens,
+                             ctx.counts["prefill_context"])
+    return 100.0 * flops / (dev_s * ctx.peak["bf16_flops_per_s"])
