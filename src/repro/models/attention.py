@@ -52,6 +52,7 @@ def init_attention(key: jax.Array, cfg: AttnConfig, quant: QuantConfig) -> Param
     return p
 
 
+@jax.named_scope("qkv")
 def _project_qkv(
     p: Params, x: jax.Array, cfg: AttnConfig, quant: QuantConfig,
     positions: jax.Array, xkv: Optional[jax.Array] = None,
@@ -183,12 +184,14 @@ def apply_attention(
     q, k, v, stats = _project_qkv(p, x, cfg, quant, positions, xkv)
     causal = cfg.causal and xkv is None
     window = cfg.sliding_window if xkv is None else 0
-    if cfg.impl == "flash":
-        ctx = _sdpa_flash(q, k, v, causal, window, kv_block=cfg.kv_block)
-    else:
-        ctx = _sdpa(q, k, v, causal, window)
-    ctx = constrain(ctx, "batch", "seq", "qkv_features")
-    y, st = apply_linear(p["wo"], ctx, quant)
+    with jax.named_scope("attention"):
+        if cfg.impl == "flash":
+            ctx = _sdpa_flash(q, k, v, causal, window,
+                              kv_block=cfg.kv_block)
+        else:
+            ctx = _sdpa(q, k, v, causal, window)
+        ctx = constrain(ctx, "batch", "seq", "qkv_features")
+        y, st = apply_linear(p["wo"], ctx, quant)
     stats.update(st)
     return y, stats
 
@@ -238,55 +241,57 @@ def decode_attention(
     lv = jnp.broadcast_to(length, (b,)) if jnp.ndim(length) == 0 else length
     pos = lv[:, None]
     q, k_new, v_new, stats = _project_qkv(p, x, cfg, quant, pos)
-    k, v = cache["k"], cache["v"]
-    s_kv = k.shape[1]
-    groups = cfg.n_heads // cfg.n_kv_heads
-    qh = q.reshape(b, 1, cfg.n_kv_heads, groups, cfg.head_dim)
-    # compute in the cache dtype (bf16) with f32 accumulation: upcasting
-    # `k` would convert (and loop-carry) the entire stacked cache in f32
-    logits = jnp.einsum(
-        "bskgd,btkd->bkgst", qh.astype(k.dtype), k,
-        preferred_element_type=jnp.float32,
-    )
-    kpos = jnp.arange(s_kv)
-    valid = kpos[None, :] < lv[:, None]              # past tokens only
-    if cfg.sliding_window > 0:
-        valid &= kpos[None, :] > lv[:, None] - cfg.sliding_window
-    logits = jnp.where(valid[:, None, None, None], logits, NEG_INF)
-    # the new token's own k as an explicit extra column
-    logit_new = jnp.einsum(
-        "bskgd,btkd->bkgst", qh.astype(k_new.dtype),
-        k_new.astype(k_new.dtype), preferred_element_type=jnp.float32,
-    )
-    scale = 1.0 / math.sqrt(cfg.head_dim)
-    full = jnp.concatenate([logits, logit_new], axis=-1) * scale
-    probs = jax.nn.softmax(full.astype(jnp.float32), axis=-1)
-    p_past, p_new = probs[..., :-1], probs[..., -1:]
-    ctx = jnp.einsum(
-        "bkgst,btkd->bskgd", p_past.astype(k.dtype), v,
-        preferred_element_type=jnp.float32,
-    ) + jnp.einsum(
-        "bkgst,btkd->bskgd", p_new.astype(v_new.dtype), v_new,
-        preferred_element_type=jnp.float32,
-    )
-    ctx = ctx.astype(q.dtype).reshape(b, 1, cfg.n_heads * cfg.head_dim)
-    y, st = apply_linear(p["wo"], ctx, quant)
+    with jax.named_scope("attention"):
+        k, v = cache["k"], cache["v"]
+        s_kv = k.shape[1]
+        groups = cfg.n_heads // cfg.n_kv_heads
+        qh = q.reshape(b, 1, cfg.n_kv_heads, groups, cfg.head_dim)
+        # compute in the cache dtype (bf16) with f32 accumulation: upcasting
+        # `k` would convert (and loop-carry) the entire stacked cache in f32
+        logits = jnp.einsum(
+            "bskgd,btkd->bkgst", qh.astype(k.dtype), k,
+            preferred_element_type=jnp.float32,
+        )
+        kpos = jnp.arange(s_kv)
+        valid = kpos[None, :] < lv[:, None]              # past tokens only
+        if cfg.sliding_window > 0:
+            valid &= kpos[None, :] > lv[:, None] - cfg.sliding_window
+        logits = jnp.where(valid[:, None, None, None], logits, NEG_INF)
+        # the new token's own k as an explicit extra column
+        logit_new = jnp.einsum(
+            "bskgd,btkd->bkgst", qh.astype(k_new.dtype),
+            k_new.astype(k_new.dtype), preferred_element_type=jnp.float32,
+        )
+        scale = 1.0 / math.sqrt(cfg.head_dim)
+        full = jnp.concatenate([logits, logit_new], axis=-1) * scale
+        probs = jax.nn.softmax(full.astype(jnp.float32), axis=-1)
+        p_past, p_new = probs[..., :-1], probs[..., -1:]
+        ctx = jnp.einsum(
+            "bkgst,btkd->bskgd", p_past.astype(k.dtype), v,
+            preferred_element_type=jnp.float32,
+        ) + jnp.einsum(
+            "bkgst,btkd->bskgd", p_new.astype(v_new.dtype), v_new,
+            preferred_element_type=jnp.float32,
+        )
+        ctx = ctx.astype(q.dtype).reshape(b, 1, cfg.n_heads * cfg.head_dim)
+        y, st = apply_linear(p["wo"], ctx, quant)
     stats.update(st)
     if defer_update:
         return y, (k_new, v_new), stats
-    if jnp.ndim(length) == 0:
-        k = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), length, axis=1
-        )
-        v = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), length, axis=1
-        )
-    else:
-        write = jax.vmap(
-            lambda c, u, l: jax.lax.dynamic_update_slice(c, u, (l, 0, 0))
-        )
-        k = write(cache["k"], k_new.astype(cache["k"].dtype), lv)
-        v = write(cache["v"], v_new.astype(cache["v"].dtype), lv)
+    with jax.named_scope("kv_commit"):
+        if jnp.ndim(length) == 0:
+            k = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k_new.astype(cache["k"].dtype), length, axis=1
+            )
+            v = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v_new.astype(cache["v"].dtype), length, axis=1
+            )
+        else:
+            write = jax.vmap(
+                lambda c, u, l: jax.lax.dynamic_update_slice(c, u, (l, 0, 0))
+            )
+            k = write(cache["k"], k_new.astype(cache["k"].dtype), lv)
+            v = write(cache["v"], v_new.astype(cache["v"].dtype), lv)
     new_cache = {"k": k, "v": v, "length": length + 1}
     return y, new_cache, stats
 

@@ -33,6 +33,12 @@ greedy hot loop runs through ``decode_multi_step`` /
 ``decode_multi_step_paged``, a device-side ``lax.while_loop`` that takes
 up to ``decode_horizon`` steps per host round-trip (on-device argmax,
 per-slot EOS/budget flags, retirement masks via ``step_mask``).
+
+Named scopes mark the parts of every step in the compiled programs' op
+metadata (``op_name``), so a device trace can sum time per part:
+``qkv``, ``attention``, ``kv_commit``, ``ffn``, ``lm_head`` and
+``sample``. An op under none of them (embedding, norms, loop
+bookkeeping, copies XLA inserts) carries no scope.
 """
 from __future__ import annotations
 
@@ -351,6 +357,7 @@ def _gather_pages(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     return g.reshape(b, mb * pool.shape[1], *pool.shape[2:])
 
 
+@jax.named_scope("kv_commit")
 def _commit_kv_paged(kv: Dict, upd: Dict, length: jax.Array,
                      block_tables: jax.Array, step_mask=None) -> Dict:
     """Write all layers' new-token K/V into each slot's current page.
@@ -456,12 +463,15 @@ def _attn_decode_one_paged_kernel(lp, x, k_pool, v_pool, block_tables,
     qh, k_new, v_new, _ = attn_mod._project_qkv(
         lp["attn"], xin, acfg, q, lv[:, None]
     )
-    ctx = paged_fn(
-        qh[:, 0], k_pool, v_pool, block_tables, lv,
-        k_new[:, 0].astype(k_pool.dtype), v_new[:, 0].astype(v_pool.dtype),
-    )
-    ctx = ctx.astype(x.dtype).reshape(b, 1, acfg.n_heads * acfg.head_dim)
-    h, _ = apply_linear(lp["attn"]["wo"], ctx, q)
+    with jax.named_scope("attention"):
+        ctx = paged_fn(
+            qh[:, 0], k_pool, v_pool, block_tables, lv,
+            k_new[:, 0].astype(k_pool.dtype),
+            v_new[:, 0].astype(v_pool.dtype),
+        )
+        ctx = ctx.astype(x.dtype).reshape(b, 1,
+                                          acfg.n_heads * acfg.head_dim)
+        h, _ = apply_linear(lp["attn"]["wo"], ctx, q)
     kv_out = {"k_new": k_new.astype(k_pool.dtype),
               "v_new": v_new.astype(v_pool.dtype)}
     return _ffn_block(lp, x + h, cfg, q), kv_out
@@ -548,13 +558,14 @@ def prefill_paged_suffix(
         xin = L.apply_norm(cfg.norm_type, lp["norm1"], x_)
         qh, kh, vh, _ = attn_mod._project_qkv(lp["attn"], xin, acfg, q,
                                               positions)
-        ctx = _prefix_sdpa(
-            qh, kh, vh,
-            _gather_pages(k_l, block_tables),
-            _gather_pages(v_l, block_tables),
-            lv, cfg.sliding_window,
-        )
-        h, _ = apply_linear(lp["attn"]["wo"], ctx, q)
+        with jax.named_scope("attention"):
+            ctx = _prefix_sdpa(
+                qh, kh, vh,
+                _gather_pages(k_l, block_tables),
+                _gather_pages(v_l, block_tables),
+                lv, cfg.sliding_window,
+            )
+            h, _ = apply_linear(lp["attn"]["wo"], ctx, q)
         x2 = _ffn_block(lp, x_ + h, cfg, q, per_token=per_token_ffn)
         return x2, (kh.astype(k_l.dtype), vh.astype(v_l.dtype))
 
@@ -571,6 +582,7 @@ def prefill_paged_suffix(
 # decode step
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kv_commit")
 def _commit_kv(kv, upd, length, step_mask=None):
     """Write all layers' new-token K/V with ONE tiny in-place update
     (never rewrite the stacked cache inside the layer scan).
@@ -625,6 +637,7 @@ def _select_slots(step_mask, new, old):
     )
 
 
+@jax.named_scope("ffn")
 def _ffn_block(lp, x, cfg: ArchConfig, q, per_token: bool = False):
     """Post-attention block tail (norm2 + MoE-or-MLP, dense residual)
     shared by the prefill, decode and paged-suffix paths.
@@ -912,8 +925,9 @@ def prefill(
         xin = L.apply_norm(cfg.norm_type, nrm, x_)
         pos = jnp.broadcast_to(jnp.arange(s), (b, s))
         qh, kh, vh, _ = attn_mod._project_qkv(ap, xin, attn_config(cfg), q, pos)
-        ctx = attn_mod._sdpa(qh, kh, vh, True, cfg.sliding_window)
-        h, _ = apply_linear(ap["wo"], ctx, q)
+        with jax.named_scope("attention"):
+            ctx = attn_mod._sdpa(qh, kh, vh, True, cfg.sliding_window)
+            h, _ = apply_linear(ap["wo"], ctx, q)
         x_ = x_ + h
         if shared:
             h, _ = L.apply_mlp(
@@ -930,6 +944,7 @@ def prefill(
             x_ = x_ + h
         return _ffn_block(lp, x_, cfg, q), (kh, vh)
 
+    @jax.named_scope("kv_commit")
     def write_kv(kv_stacked, k_layers, v_layers):
         k = jax.lax.dynamic_update_slice_in_dim(
             kv_stacked["k"], k_layers.astype(kv_stacked["k"].dtype), 0, axis=2
@@ -1083,8 +1098,9 @@ def _multi_step_loop(step_fn, cache, last_tok, live, eos_ids, budget,
         cache, last, done, buf, emitted, rem, s = carry
         emit = ~done
         logits, cache = step_fn(cache, last[:, None], emit)
-        tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        tok = constrain(jnp.where(emit, tok, -1), "batch")
+        with jax.named_scope("sample"):
+            tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            tok = constrain(jnp.where(emit, tok, -1), "batch")
         buf = jax.lax.dynamic_update_slice(buf, tok[:, None], (0, s))
         emitted = emitted + emit.astype(jnp.int32)
         rem = rem - emit.astype(jnp.int32)
@@ -1199,9 +1215,10 @@ def decode_verify(
         xin = L.apply_norm(cfg.norm_type, lp["norm1"], x_)
         qh, kh, vh, _ = attn_mod._project_qkv(lp["attn"], xin, acfg, q,
                                               positions)
-        ctx = _prefix_sdpa(qh, kh, vh, k_l, v_l, lengths,
-                           cfg.sliding_window)
-        h, _ = apply_linear(lp["attn"]["wo"], ctx, q)
+        with jax.named_scope("attention"):
+            ctx = _prefix_sdpa(qh, kh, vh, k_l, v_l, lengths,
+                               cfg.sliding_window)
+            h, _ = apply_linear(lp["attn"]["wo"], ctx, q)
         x_ = x_ + h
         if has_cross:
             h, _ = attn_mod.decode_cross_attention(
@@ -1242,8 +1259,9 @@ def decode_propose(
         c, tok = carry
         logits, c = decode_step(params, cfg, tok[:, None], c,
                                 step_mask=live)
-        nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        nxt = jnp.where(live, nxt, tok)
+        with jax.named_scope("sample"):
+            nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+            nxt = jnp.where(live, nxt, tok)
         return (c, nxt), nxt
 
     (cache, _), toks = jax.lax.scan(
@@ -1251,6 +1269,7 @@ def decode_propose(
     return jnp.moveaxis(toks, 0, 1), cache
 
 
+@jax.named_scope("kv_commit")
 def paged_verify_commit(
     kv: Dict, upd: Dict, lengths: jax.Array, block_tables: jax.Array,
     live: jax.Array,

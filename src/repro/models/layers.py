@@ -134,6 +134,7 @@ def apply_embedding(p: Params, ids: jax.Array) -> jax.Array:
     return constrain(jnp.take(p["table"], ids, axis=0), "batch", "seq", "embed")
 
 
+@jax.named_scope("lm_head")
 def apply_lm_head(
     p_emb: Params, x: jax.Array, head: Optional[Params] = None
 ) -> jax.Array:

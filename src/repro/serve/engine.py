@@ -31,6 +31,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.configs.base import ArchConfig
@@ -51,6 +52,7 @@ from repro.serve.paged_kv import PagedKVManager, PoolExhausted
 from repro.serve.scheduler import (
     ContiguousAdmitter,
     EngineConfig,
+    EngineTelemetry,
     EnergyModel,
     PagedAdmitter,
     Request,
@@ -73,7 +75,7 @@ _DEFAULT_ENC_LEN = 8
 _RECURRENT_FAMILIES = ("hybrid", "ssm")
 
 
-class ServeEngine:
+class ServeEngine(EngineTelemetry):
     """Submit prompts, then :meth:`run` to completion (or :meth:`step`
     one scheduling round at a time for streaming callers);
     ``stats()`` exposes scheduler counters on top of throughput."""
@@ -133,19 +135,9 @@ class ServeEngine:
                 params, mesh, self._rules
             )
 
-        # scheduler telemetry (continuous mode)
-        self.decode_steps = 0
-        self.host_syncs = 0              # decode round-trips (jit + drain)
-        self.decode_wall_s = 0.0         # wall time inside decode syncs
-        self.prefill_calls = 0
-        self.prefill_tokens = 0          # true (unpadded) tokens prefilled
-        self.cached_prefix_tokens = 0    # prompt tokens served from pages
-        self.step_occupancy: List[float] = []
-        self.admissions: List[Dict[str, int]] = []   # {step, uid, slot}
-        # speculative decoding telemetry
-        self.spec_rounds = 0
-        self.spec_proposed = 0           # draft tokens put up for verify
-        self.spec_accepted = 0           # draft tokens the verify kept
+        # scheduler telemetry (continuous mode): plain scalars, so a
+        # long-running server's counters never grow
+        self._zero_counters()
 
         # hwmodel energy pricing: admission/executors account through
         # this ONE hook; the cost-aware policy prices via the same model
@@ -256,6 +248,10 @@ class ServeEngine:
                              "static mode only drains through run()")
         if self.drained:
             return {}
+        with TraceAnnotation("serve.step"):
+            return self._round()
+
+    def _round(self) -> Dict[int, List[int]]:
         self._start()                    # idempotent pool allocation
         before = {r.uid: (r, len(r.output)) for r in self.queue}
         for r in self.state.slots:
@@ -265,11 +261,13 @@ class ServeEngine:
         # pool/budget can't take the queue head — retirement frees both,
         # so fall through to the executor rather than spin here.
         stalled = False
-        while self.queue and not stalled:
-            free = self.state.free()
-            if not free:
-                break
-            stalled = not self.admitter.admit(free)
+        if self.queue:
+            with TraceAnnotation("serve.admit"):
+                while self.queue and not stalled:
+                    free = self.state.free()
+                    if not free:
+                        break
+                    stalled = not self.admitter.admit(free)
         if self.state.any_live:
             self.executor.run_round()
         elif stalled:
@@ -323,18 +321,8 @@ class ServeEngine:
         """Clear finished requests + telemetry, keeping compiled fns
         warm and the paged prefix index populated (post-warm-up runs)."""
         self.finished = []
-        self.decode_steps = 0
-        self.host_syncs = 0
-        self.decode_wall_s = 0.0
-        self.prefill_calls = 0
-        self.prefill_tokens = 0
-        self.cached_prefix_tokens = 0
+        self._zero_counters()
         self.energy.reset()
-        self.step_occupancy = []
-        self.admissions = []
-        self.spec_rounds = 0
-        self.spec_proposed = 0
-        self.spec_accepted = 0
         if hasattr(self.policy, "deferrals"):
             self.policy.deferrals = 0
         if self._mgr is not None:
@@ -343,19 +331,6 @@ class ServeEngine:
     def reset_counters(self) -> None:
         """Alias for :meth:`reset_stats` (paged-KV manager naming)."""
         self.reset_stats()
-
-    # -- accounting hooks (the single energy/prefill attribution sites) ----
-    def account_prefill(self, n_tokens: int) -> None:
-        """One prefill call ran ``n_tokens`` TRUE prompt tokens (reused
-        prefix pages cost nothing and are not reported here)."""
-        self.prefill_calls += 1
-        self.prefill_tokens += n_tokens
-        self.energy.add(n_tokens)
-
-    def account_decode(self, n_tokens: int) -> None:
-        """A decode round emitted ``n_tokens`` true tokens (masked
-        no-op steps of retired rows excluded)."""
-        self.energy.add(n_tokens)
 
     @property
     def energy_tokens(self) -> int:
@@ -368,7 +343,9 @@ class ServeEngine:
         return self.energy.report(styles=styles, occupancy=occupancy)
 
     def stats(self) -> Dict[str, float]:
-        occ = float(np.mean(self.step_occupancy)) if self.step_occupancy else 0.0
+        occ = (self.occupied_slot_steps
+               / (self.decode_steps * self.ecfg.max_batch)
+               if self.decode_steps else 0.0)
         out = {
             "mode": self.mode,
             "decode_steps": self.decode_steps,
@@ -378,9 +355,14 @@ class ServeEngine:
                             if self.decode_steps else 0.0),
             "prefill_calls": self.prefill_calls,
             "prefill_tokens": self.prefill_tokens,
+            "prefill_padded_tokens": self.prefill_padded_tokens,
             "cached_prefix_tokens": self.cached_prefix_tokens,
             "mean_slot_occupancy": occ,
-            "admissions": len(self.admissions),
+            "occupied_slot_steps": self.occupied_slot_steps,
+            "admissions": self.admitted,
+            "admitted": self.admitted,
+            "queue_wait_s": self.queue_wait_s,
+            "device_wait_s": self.device_wait_s,
             "mesh": (None if self.mesh is None else
                      "x".join(f"{k}={v}" for k, v in self.mesh.shape.items())),
             "admission_policy": self.policy.name,

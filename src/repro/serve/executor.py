@@ -30,6 +30,13 @@ retires finished slots:
     the static oracle mode: a fixed batch prefills together and
     decodes in lockstep until every member finishes.
 
+Every continuous-mode round traces the same three host spans, on the
+profiler's clock beside the device's ops (about half a microsecond
+each when no profiler runs): ``serve.decode.dispatch`` (host prep,
+transfers and the jitted calls), ``serve.decode.wait`` (blocked
+reading the results, ``fetch``) and ``serve.decode.drain`` (token
+hand-out, retirement, accounting).
+
 Executors never touch the queue or the admission policy, which is what
 makes prefill/decode disaggregation a scheduler-level change: two
 engines running different executors can pass paged blocks without
@@ -50,6 +57,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import decode as D
 from repro.serve.scheduler import next_pow2, right_pad
@@ -236,6 +244,18 @@ class DeviceHorizonExecutor(_Executor):
 
     def run_round(self) -> None:
         eng = self.eng
+        t0 = time.time()
+        with TraceAnnotation("serve.decode.dispatch"):
+            out = self._dispatch()
+        buf, emitted, done, last, steps = eng.fetch(
+            "serve.decode.wait", *out)
+        with TraceAnnotation("serve.decode.drain"):
+            self._drain(buf, emitted, done, last, int(steps), t0)
+
+    def _dispatch(self):
+        """Host prep and the horizon call; returns its device results
+        ``(buf, emitted, done, last, steps)`` unread."""
+        eng = self.eng
         slots = self.state.slots
         n = eng.ecfg.max_batch
         h = eng.ecfg.decode_horizon
@@ -248,7 +268,6 @@ class DeviceHorizonExecutor(_Executor):
                 continue
             budget[i] = r.max_new_tokens - len(r.output)
             eos[i] = r.eos_id
-        t0 = time.time()
         if paged:
             mgr = self.state.mgr
             # a CoW valve can only resolve on the host; if one would
@@ -295,16 +314,22 @@ class DeviceHorizonExecutor(_Executor):
                 eng.params, eng._cache, jnp.asarray(self.state.last_tok),
                 jnp.asarray(live), jnp.asarray(eos), jnp.asarray(budget), h)
         eng._cache = cache
-        buf, emitted = np.asarray(buf), np.asarray(emitted)
-        done, last, steps = np.asarray(done), np.asarray(last), int(steps)
+        return buf, emitted, done, last, steps
+
+    def _drain(self, buf, emitted, done, last, steps: int,
+               t0: float) -> None:
+        """Hand out each slot's tokens, retire finished slots and
+        account the round."""
+        eng = self.eng
+        slots = self.state.slots
         now = time.time()
         eng.host_syncs += 1
         eng.decode_wall_s += now - t0
         eng.decode_steps += steps
-        # occupancy per DEVICE step: slot i was live at step s of the
-        # horizon iff it emitted more than s tokens
-        for s in range(steps):
-            eng.step_occupancy.append(float(np.sum(emitted > s)) / n)
+        # slot i was live at step s of the horizon iff it emitted more
+        # than s tokens, so the live slots summed over the steps are the
+        # tokens emitted
+        eng.occupied_slot_steps += int(np.minimum(emitted, steps).sum())
         new_tokens = 0
         for i, r in enumerate(slots):
             if r is None:
@@ -328,41 +353,43 @@ class HostLoopExecutor(_Executor):
     def run_round(self) -> None:
         eng = self.eng
         slots = self.state.slots
-        n = eng.ecfg.max_batch
         paged = eng.ecfg.paged
-        eng.step_occupancy.append(sum(s is not None for s in slots) / n)
         t0 = time.time()
-        if paged:
-            # grow each live slot's table by one token (a fresh
-            # page at block boundaries, copy-on-write if shared)
-            for i, s in enumerate(slots):
-                if s is None:
+        with TraceAnnotation("serve.decode.dispatch"):
+            if paged:
+                # grow each live slot's table by one token (a fresh
+                # page at block boundaries, copy-on-write if shared)
+                for i, s in enumerate(slots):
+                    if s is None:
+                        continue
+                    self.state.prepare_append(i)
+                logits, cache = eng._decode_paged(
+                    eng.params, jnp.asarray(self.state.last_tok)[:, None],
+                    eng._cache, jnp.asarray(self.state.mgr.tables))
+            else:
+                logits, cache = eng._decode(
+                    eng.params, jnp.asarray(self.state.last_tok)[:, None],
+                    eng._cache)
+            eng._cache = cache
+            nxt = eng._sample(logits[:, 0])
+        nxt, = eng.fetch("serve.decode.wait", nxt)
+        with TraceAnnotation("serve.decode.drain"):
+            eng.decode_steps += 1
+            eng.host_syncs += 1
+            now = time.time()
+            eng.decode_wall_s += now - t0
+            eng.occupied_slot_steps += sum(s is not None for s in slots)
+            new_tokens = 0
+            for i, r in enumerate(slots):
+                if r is None:
                     continue
-                self.state.prepare_append(i)
-            logits, cache = eng._decode_paged(
-                eng.params, jnp.asarray(self.state.last_tok)[:, None],
-                eng._cache, jnp.asarray(self.state.mgr.tables))
-        else:
-            logits, cache = eng._decode(
-                eng.params, jnp.asarray(self.state.last_tok)[:, None],
-                eng._cache)
-        eng._cache = cache
-        nxt = np.asarray(eng._sample(logits[:, 0]))
-        eng.decode_steps += 1
-        eng.host_syncs += 1
-        now = time.time()
-        eng.decode_wall_s += now - t0
-        new_tokens = 0
-        for i, r in enumerate(slots):
-            if r is None:
-                continue
-            t = int(nxt[i])
-            r.output.append(t)
-            new_tokens += 1
-            self.state.last_tok[i] = t
-            if t == r.eos_id or len(r.output) >= r.max_new_tokens:
-                self.retire(i, r, now)       # freed THIS step
-        eng.account_decode(new_tokens)
+                t = int(nxt[i])
+                r.output.append(t)
+                new_tokens += 1
+                self.state.last_tok[i] = t
+                if t == r.eos_id or len(r.output) >= r.max_new_tokens:
+                    self.retire(i, r, now)       # freed THIS step
+            eng.account_decode(new_tokens)
 
 
 class SpecRoundExecutor(_Executor):
@@ -393,81 +420,83 @@ class SpecRoundExecutor(_Executor):
         live = self.state.live_flags()
         n_live = int(live.sum())
         t0 = time.time()
-        k_round = k
-        base_len = None
-        if paged:
-            mgr = self.state.mgr
-            bs = eng.ecfg.block_size
-            base_len = [int(mgr.lengths[i]) for i in range(n)]
-            need = 0
-            for i, s in enumerate(slots):
-                if s is None:
-                    continue
-                end = base_len[i] + k + 1
-                need += max(0, -(-end // bs)
-                            - len(mgr.slot_blocks(i)))
-            if need > mgr.pool.free_blocks:
-                k_round = 0
-            for i, s in enumerate(slots):
-                if s is None:
-                    continue
-                for _ in range(k_round + 1):
-                    self.state.prepare_append(i)
-        live_dev = jnp.asarray(live)
-        last_dev = jnp.asarray(self.state.last_tok)
-        props, eng._draft_cache = eng._draft_propose(
-            eng.draft_params, eng._draft_cache, last_dev, live_dev,
-            k_round + 1)
-        if paged:
-            logits, eng._cache = eng._verify_paged(
-                eng.params, eng._cache,
-                jnp.asarray(self.state.mgr.tables),
-                live_dev, last_dev, props)
-        else:
-            logits, eng._cache = eng._verify(eng.params, eng._cache,
-                                             last_dev, props)
+        with TraceAnnotation("serve.decode.dispatch"):
+            k_round = k
+            base_len = None
+            if paged:
+                mgr = self.state.mgr
+                bs = eng.ecfg.block_size
+                base_len = [int(mgr.lengths[i]) for i in range(n)]
+                need = 0
+                for i, s in enumerate(slots):
+                    if s is None:
+                        continue
+                    end = base_len[i] + k + 1
+                    need += max(0, -(-end // bs)
+                                - len(mgr.slot_blocks(i)))
+                if need > mgr.pool.free_blocks:
+                    k_round = 0
+                for i, s in enumerate(slots):
+                    if s is None:
+                        continue
+                    for _ in range(k_round + 1):
+                        self.state.prepare_append(i)
+            live_dev = jnp.asarray(live)
+            last_dev = jnp.asarray(self.state.last_tok)
+            props, eng._draft_cache = eng._draft_propose(
+                eng.draft_params, eng._draft_cache, last_dev, live_dev,
+                k_round + 1)
+            if paged:
+                logits, eng._cache = eng._verify_paged(
+                    eng.params, eng._cache,
+                    jnp.asarray(self.state.mgr.tables),
+                    live_dev, last_dev, props)
+            else:
+                logits, eng._cache = eng._verify(eng.params, eng._cache,
+                                                 last_dev, props)
+            m = jnp.argmax(logits, axis=-1)             # (n, k_round+1)
         # one host sync per round: the proposals and the verify argmaxes
         # land together (async dispatch keeps the draft/verify pipelined)
-        m = np.asarray(jnp.argmax(logits, axis=-1))     # (n, k_round+1)
-        props = np.asarray(props)
-        now = time.time()
-        eng.host_syncs += 1
-        eng.decode_wall_s += now - t0
-        eng.decode_steps += 1
-        eng.spec_rounds += 1
-        eng.step_occupancy.append(n_live / n)
-        new_tokens = 0
-        for i in range(n):
-            r = slots[i]
-            if r is None:
-                continue
-            a = 0
-            while a < k_round and props[i, a] == m[i, a]:
-                a += 1
-            eng.spec_proposed += k_round
-            eng.spec_accepted += a
-            for t in m[i, :a + 1]:
-                t = int(t)
-                r.output.append(t)
-                new_tokens += 1
-                self.state.last_tok[i] = t
-                if t == r.eos_id or len(r.output) >= r.max_new_tokens:
-                    self.retire(i, r, now)
-                    break
-            if paged and slots[i] is not None:
-                self.state.truncate(i, base_len[i] + a + 1)
-        eng.account_decode(new_tokens)
-        # the rollback: both caches' lengths snap to the accepted
-        # position (free slots to 0); junk K/V above the watermark is
-        # never attended and the next round overwrites it in place
-        lens = np.zeros((n,), np.int32)
-        for i, r in enumerate(slots):
-            if r is not None:
-                lens[i] = (eng._patch_len + len(r.prompt)
-                           + len(r.output) - 1)
-        self.state.set_lengths(lens)
-        eng._draft_cache = eng._set_len(eng._draft_cache,
-                                        jnp.asarray(lens))
+        m, props = eng.fetch("serve.decode.wait", m, props)
+        with TraceAnnotation("serve.decode.drain"):
+            now = time.time()
+            eng.host_syncs += 1
+            eng.decode_wall_s += now - t0
+            eng.decode_steps += 1
+            eng.spec_rounds += 1
+            eng.occupied_slot_steps += n_live
+            new_tokens = 0
+            for i in range(n):
+                r = slots[i]
+                if r is None:
+                    continue
+                a = 0
+                while a < k_round and props[i, a] == m[i, a]:
+                    a += 1
+                eng.spec_proposed += k_round
+                eng.spec_accepted += a
+                for t in m[i, :a + 1]:
+                    t = int(t)
+                    r.output.append(t)
+                    new_tokens += 1
+                    self.state.last_tok[i] = t
+                    if t == r.eos_id or len(r.output) >= r.max_new_tokens:
+                        self.retire(i, r, now)
+                        break
+                if paged and slots[i] is not None:
+                    self.state.truncate(i, base_len[i] + a + 1)
+            eng.account_decode(new_tokens)
+            # the rollback: both caches' lengths snap to the accepted
+            # position (free slots to 0); junk K/V above the watermark is
+            # never attended and the next round overwrites it in place
+            lens = np.zeros((n,), np.int32)
+            for i, r in enumerate(slots):
+                if r is not None:
+                    lens[i] = (eng._patch_len + len(r.prompt)
+                               + len(r.output) - 1)
+            self.state.set_lengths(lens)
+            eng._draft_cache = eng._set_len(eng._draft_cache,
+                                            jnp.asarray(lens))
 
 
 class StaticBatchExecutor(_Executor):
@@ -495,7 +524,7 @@ class StaticBatchExecutor(_Executor):
         toks, lens = right_pad(reqs, bp, w)
         b = eng._prefill_batch(reqs, bp, toks, lens)
         logits, cache = eng._prefill_full(eng.params, b)
-        eng.account_prefill(sum(len(r.prompt) for r in reqs))
+        eng.account_prefill(sum(len(r.prompt) for r in reqs), bp * w)
         # each row's first token comes from its true last prompt position
         nxt = eng._sample(
             logits[jnp.arange(bp), jnp.maximum(b["lengths"] - 1, 0)])
@@ -520,7 +549,7 @@ class StaticBatchExecutor(_Executor):
             )
             if n_alive == 0:
                 break
-            eng.step_occupancy.append(n_alive / eng.ecfg.max_batch)
+            eng.occupied_slot_steps += n_alive
             logits, cache = eng._decode(
                 eng.params, jnp.asarray(nxt)[:, None], cache
             )

@@ -5,7 +5,7 @@ the :class:`ServeEngine` facade (``serve/engine.py``) only wires the
 layers together, and the executors (``serve/executor.py``) only run
 what admission already placed.
 
-Three pieces:
+Four pieces:
 
 ``Request`` / ``EngineConfig``
     the public request record and engine knob set.
@@ -37,6 +37,11 @@ Three pieces:
     scatter, radix prefix reuse and page-pool headroom math. They
     consult the policy for the take decision and the engine for
     compiled functions and telemetry.
+
+``EngineTelemetry``
+    the engine's scalar counters and the accounting hooks admitters
+    and executors call, ``fetch`` among them: every device read under
+    a ``serve.*.wait`` profiler span.
 """
 from __future__ import annotations
 
@@ -48,6 +53,7 @@ from typing import (
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.models import decode as D
@@ -73,7 +79,9 @@ class Request:
     t_enqueue: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
+    t_admit: float = 0.0          # left the queue for its prefill
     slot: int = -1                # decode slot served in (continuous mode)
+    admit_step: int = -1          # engine decode step when bound to it
     extra_idx: int = -1           # side-input row (-1: positional by uid)
 
 
@@ -406,6 +414,63 @@ class EnergyModel:
 
 # -- admission policies -----------------------------------------------------
 
+class EngineTelemetry:
+    """The serving engine's telemetry: plain scalar counters, so a
+    long-running server's never grow, and the hooks admitters and
+    executors account through (the single energy/prefill attribution
+    sites). :class:`~repro.serve.engine.ServeEngine` inherits it, so the
+    counters are engine attributes; it supplies ``energy``, an
+    :class:`EnergyModel`."""
+
+    def _zero_counters(self) -> None:
+        self.decode_steps = 0
+        self.host_syncs = 0              # decode round-trips (jit + drain)
+        self.decode_wall_s = 0.0         # wall time inside decode syncs
+        self.device_wait_s = 0.0         # blocked in the serve.*.wait reads
+        self.prefill_calls = 0
+        self.prefill_tokens = 0          # true (unpadded) tokens prefilled
+        self.prefill_padded_tokens = 0   # rows x width the prefills computed
+        self.cached_prefix_tokens = 0    # prompt tokens served from pages
+        self.occupied_slot_steps = 0     # live slots summed over decode steps
+        self.admitted = 0                # requests bound to a slot
+        self.queue_wait_s = 0.0          # their summed time in the queue
+        # speculative decoding telemetry
+        self.spec_rounds = 0
+        self.spec_proposed = 0           # draft tokens put up for verify
+        self.spec_accepted = 0           # draft tokens the verify kept
+
+    def account_prefill(self, n_tokens: int, padded_tokens: int) -> None:
+        """One prefill call ran ``n_tokens`` TRUE prompt tokens in a
+        block of ``padded_tokens`` (rows x bucket width); reused prefix
+        pages cost nothing and are not reported here."""
+        self.prefill_calls += 1
+        self.prefill_tokens += n_tokens
+        self.prefill_padded_tokens += padded_tokens
+        self.energy.add(n_tokens)
+
+    def account_admit(self, r: Request) -> None:
+        """``r`` was just bound to its slot; its queue wait ran from
+        ``submit`` to leaving the queue (``t_admit``)."""
+        r.admit_step = self.decode_steps
+        self.admitted += 1
+        self.queue_wait_s += r.t_admit - r.t_enqueue
+
+    def account_decode(self, n_tokens: int) -> None:
+        """A decode round emitted ``n_tokens`` true tokens (masked
+        no-op steps of retired rows excluded)."""
+        self.energy.add(n_tokens)
+
+    def fetch(self, span: str, *arrays) -> List[np.ndarray]:
+        """Read device results to the host under ``span`` (one of the
+        ``serve.*.wait`` names); the time blocked counts to
+        ``device_wait_s``."""
+        t = time.perf_counter()
+        with TraceAnnotation(span):
+            out = [np.asarray(a) for a in arrays]
+        self.device_wait_s += time.perf_counter() - t
+        return out
+
+
 class AdmissionPolicy(Protocol):
     """The admission decision: which queued requests join this wave.
 
@@ -536,8 +601,10 @@ class ContiguousAdmitter:
         take = eng.policy.take(queue, limit, eng._bucket_of, live=live)
         if not take:
             return False
+        now = time.time()
         for r in take:
             queue.remove(r)
+            r.t_admit = now
 
         m = len(take)
         mp = min(next_pow2(m), eng.ecfg.prefill_batch)
@@ -548,11 +615,12 @@ class ContiguousAdmitter:
         dcache = None
         if eng._spec_k:
             _, dcache = eng._draft_prefill(eng.draft_params, b)
-        eng.account_prefill(sum(len(r.prompt) for r in take))
+        eng.account_prefill(sum(len(r.prompt) for r in take), mp * w)
         # each row's next token comes from its true last prompt position
         idx = jnp.asarray([len(r.prompt) - 1 for r in take]
                           + [0] * (mp - m))
-        first = np.asarray(eng._sample(logits[jnp.arange(mp), idx]))
+        first = eng.fetch("serve.prefill.wait",
+                          eng._sample(logits[jnp.arange(mp), idx]))[0]
         now = time.time()
         for i, r in enumerate(take):
             r.t_first_token = now
@@ -568,8 +636,7 @@ class ContiguousAdmitter:
                 eng._draft_cache = eng._draft_insert(
                     eng._draft_cache, dcache, i, slot, ln)
             eng.state.bind(r, slot, t)
-            eng.admissions.append(
-                {"step": eng.decode_steps, "uid": r.uid, "slot": slot})
+            eng.account_admit(r)
         return True
 
 
@@ -642,8 +709,7 @@ class PagedAdmitter:
             self.mgr.retire(slot)  # pages freed; the prefix stays indexed
             return
         eng.state.bind(r, slot, token)
-        eng.admissions.append(
-            {"step": eng.decode_steps, "uid": r.uid, "slot": slot})
+        eng.account_admit(r)
 
     def _admit_suffix(self, free: List[int]) -> bool:
         # peek, don't pop: if the pool can't hold the head's pages the
@@ -667,6 +733,7 @@ class PagedAdmitter:
         except PoolExhausted:
             return False
         eng.queue.pop(0)
+        r.t_admit = time.time()
         free.pop(0)
         suffix = r.prompt[cached:]
         w = eng._bucket(len(suffix))
@@ -683,13 +750,14 @@ class PagedAdmitter:
             np.int32(cached),
         )
         # reused prefix costs nothing — only the suffix runs the model
-        eng.account_prefill(len(suffix))
+        eng.account_prefill(len(suffix), w)
         eng.cached_prefix_tokens += cached
         eng._cache = eng._insert_paged(
             eng._cache, src, 0, slot, jnp.asarray(self.mgr.tables[slot]),
             np.int32(cached), len(prompt))
         self.mgr.register(slot, prompt)
-        first = np.asarray(eng._sample(logits[:, len(suffix) - 1]))
+        first = eng.fetch("serve.prefill.wait",
+                          eng._sample(logits[:, len(suffix) - 1]))[0]
         self._place(r, slot, int(first[0]), time.time())
         if eng._spec_k and eng.state.slots[slot] is r:
             # the draft pool is contiguous and reuses no prefixes: it
@@ -748,8 +816,10 @@ class PagedAdmitter:
             placed.append((r, slot, prompt))
         if not placed:
             return False
+        now = time.time()
         for r, _, _ in placed:
             eng.queue.remove(r)
+            r.t_admit = now
 
         m = len(placed)
         mp = min(next_pow2(m), eng.ecfg.prefill_batch)
@@ -759,10 +829,12 @@ class PagedAdmitter:
         dcache = None
         if eng._spec_k:
             _, dcache = eng._draft_prefill(eng.draft_params, b)
-        eng.account_prefill(sum(len(r.prompt) for r, _, _ in placed))
+        eng.account_prefill(sum(len(r.prompt) for r, _, _ in placed),
+                            mp * w)
         idx = jnp.asarray([len(r.prompt) - 1 for r, _, _ in placed]
                           + [0] * (mp - m))
-        first = np.asarray(eng._sample(logits[jnp.arange(mp), idx]))
+        first = eng.fetch("serve.prefill.wait",
+                          eng._sample(logits[jnp.arange(mp), idx]))[0]
         now = time.time()
         for i, (r, slot, prompt) in enumerate(placed):
             eng._cache = eng._insert_paged(

@@ -124,7 +124,8 @@ class TestSlotReuse:
         pooled, eng = _run(params, cfg, prompts, "continuous", max_batch=1,
                            max_new=5)
         # every admission really went through the same slot
-        assert {a["slot"] for a in eng.admissions} == {0}
+        assert {r.slot for r in eng.finished} == {0}
+        assert eng.admitted == len(prompts)
         for uid, p in zip(sorted(pooled), prompts):
             seq, _ = _run(params, cfg, [p], "static", max_batch=1, max_new=5)
             assert pooled[uid] == seq[1], \

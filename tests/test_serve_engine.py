@@ -63,9 +63,8 @@ class TestContinuousScheduling:
         assert len(done[uid_c].output) == 4
         # c was admitted mid-flight into a freed slot (both slots were
         # taken at step 0), before b finished
-        adm = {a["uid"]: a for a in eng.admissions}
-        assert adm[uid_c]["step"] > 0
-        assert adm[uid_c]["slot"] == adm[uid_a]["slot"]
+        assert done[uid_c].admit_step > 0
+        assert done[uid_c].slot == done[uid_a].slot
         assert done[uid_c].t_first_token < done[uid_b].t_done
 
     def test_batched_vs_sequential_greedy_parity(self, tiny):
