@@ -589,11 +589,17 @@ def _commit_kv(kv, upd, length, step_mask=None):
 
     ``length`` scalar: one write position for the whole batch.
     ``length`` (B,) vector: per-slot positions (continuous batching) —
-    vmapped over the batch axis so each slot lands at its own offset.
+    one scatter per leaf writes row ``(layer, b, length[b] + w)`` of
+    the ``(layers, slots, S, kv_heads, head_dim)`` pool for every
+    layer, slot and width position ``w``, in the pool's own layout (a
+    per-slot ``dynamic_update_slice`` under ``vmap`` would be lowered
+    as a transpose of the whole pool). Each start is clamped to
+    ``S - W`` exactly as ``dynamic_update_slice`` clamps it.
     ``step_mask`` (B,) bool (vector lengths only): slots with a False
-    mask get their OLD value written back — the commit is a true no-op
-    for retired slots inside :func:`decode_multi_step`, so the donated
-    cache never picks up junk from a slot that finished mid-horizon.
+    mask are pointed past the sequence axis and dropped — the commit is
+    a true no-op for retired slots inside :func:`decode_multi_step`, so
+    the donated cache never picks up junk from a slot that finished
+    mid-horizon.
     """
     if jnp.ndim(length) == 0:
         return {
@@ -602,24 +608,21 @@ def _commit_kv(kv, upd, length, step_mask=None):
             "v": jax.lax.dynamic_update_slice(
                 kv["v"], upd["v_new"], (0, 0, length, 0, 0)),
         }
+    n_slots, seq, width = (kv["k"].shape[1], kv["k"].shape[2],
+                           upd["k_new"].shape[2])
+    start = jnp.clip(length, 0, seq - width)
     if step_mask is not None:
-        read = jax.vmap(
-            lambda c, l: jax.lax.dynamic_slice_in_dim(c, l, 1, axis=1),
-            in_axes=(1, 0), out_axes=1,
-        )
-        m = step_mask[None, :, None, None, None]
-        upd = {
-            "k_new": jnp.where(m, upd["k_new"], read(kv["k"], length)),
-            "v_new": jnp.where(m, upd["v_new"], read(kv["v"], length)),
-        }
-    write = jax.vmap(
-        lambda c, u, l: jax.lax.dynamic_update_slice(c, u, (0, l, 0, 0)),
-        in_axes=(1, 1, 0), out_axes=1,
-    )
-    return {
-        "k": write(kv["k"], upd["k_new"], length),
-        "v": write(kv["v"], upd["v_new"], length),
-    }
+        start = jnp.where(step_mask, start, seq)
+    # (B, W) positions against (B, 1) slots: slot-major, distinct pairs
+    pos = start[:, None] + jnp.arange(width, dtype=start.dtype)
+    slot = jnp.arange(n_slots, dtype=start.dtype)[:, None]
+
+    def write(pool, new):
+        return pool.at[:, slot, pos].set(
+            new, mode="drop", indices_are_sorted=True, unique_indices=True)
+
+    return {"k": write(kv["k"], upd["k_new"]),
+            "v": write(kv["v"], upd["v_new"])}
 
 
 def _select_slots(step_mask, new, old):
